@@ -53,6 +53,8 @@ def main() -> None:
                     help="also write rows as JSON records to PATH")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.tables import ALL_TABLES
 
     rows = ["name,us_per_call,derived"]

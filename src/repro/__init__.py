@@ -1,17 +1,3 @@
 """repro: FlexRound (ICML 2023) as a production-grade JAX PTQ framework."""
-import jax
-
-# Sharding-invariant RNG, required for data-parallel calibration: with the
-# legacy (non-partitionable) threefry, random draws whose outputs are sharded
-# (QDrop masks over a dp-sharded minibatch) produce *different values* than
-# the same program on one device, so a sharded reconstruction could never
-# reproduce the unsharded trajectory. The partitionable scheme generates each
-# shard's bits independently yet identically to the single-device stream —
-# no collectives, same values under any sharding. Newer jax releases default
-# to True; pinning it here keeps every entry point (train, PTQ, benchmarks,
-# tests) on one stream. This is an intended trajectory change relative to
-# the legacy stream: the recon fixtures were re-recorded under it (see
-# tests/fixtures/record_fixtures.py).
-jax.config.update("jax_threefry_partitionable", True)
 
 __version__ = "1.0.0"

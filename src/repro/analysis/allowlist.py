@@ -59,7 +59,7 @@ kernel differ):
   QL305 lost-psum                 a shard_map collective reduces over the
                                   wrong mesh axis, or an output is declared
                                   replicated over a dp axis nothing reduced
-                                  (with check_rep=False hiding it)
+                                  (with check_vma=False hiding it)
   QL306 scan-collective-          a collective inside a donated-carry scan
         unconstrained             body with no sharding constraint anchoring
                                   the reduced value's layout

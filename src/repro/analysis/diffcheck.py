@@ -126,7 +126,7 @@ def _example_at(layout: str, e: int, m: int, k: int, n: int):
 
 
 @contextlib.contextmanager
-def _record_pallas(hits: List[str]):
+def record_pallas(hits: List[str]):
     """Record which Pallas kernel ``ops`` dispatches (the interpret-mode
     run); mirrors coverage's ref-side recorder."""
     import repro.kernels.ops as kops
@@ -163,7 +163,7 @@ def check_parity(layout: str, e: int, m: int, k: int, n: int) -> ParityRow:
         ref_out = jax.block_until_ready(kops.qtensor_matmul(
             x, qt, a_state=a_state, backend="xla"))
     pl_hits: List[str] = []
-    with _record_pallas(pl_hits):
+    with record_pallas(pl_hits):
         pl_out = jax.block_until_ready(kops.qtensor_matmul(
             x, qt, a_state=a_state, backend="pallas", interpret=True))
 

@@ -431,7 +431,7 @@ def _eval_jaxpr(jaxpr, in_ivals: List[Interval],
         p = eqn.primitive.name
         outs: List[Interval] = []
 
-        if p in ("pjit", "closed_call", "core_call", "remat_call", "remat",
+        if p in ("jit", "closed_call", "core_call", "remat_call", "remat",
                  "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
                  "checkpoint"):
             for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):

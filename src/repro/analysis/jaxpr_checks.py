@@ -68,7 +68,7 @@ def _all_jaxprs(jaxpr) -> Iterable[Any]:
 # --------------------------------------------------------- QL201 unused input
 def _used_invars(closed) -> List[bool]:
     """Which flat invars the jaxpr actually consumes (transitively, through
-    scan/pjit subjaxprs)."""
+    scan/jit subjaxprs)."""
     jaxpr = closed.jaxpr
     if _pe is not None:
         _, used = _pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))

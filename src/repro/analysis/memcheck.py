@@ -30,7 +30,7 @@ Liveness model
 --------------
 One linear scan per jaxpr: a buffer materializes when its defining equation
 runs (while that equation's operands are still held) and dies after its
-last consuming equation, unless it is an output. Sub-jaxprs (pjit / scan /
+last consuming equation, unless it is an output. Sub-jaxprs (jit / scan /
 while / cond / shard_map) are walked recursively; their inner peak minus
 the bytes of the invars that alias outer operands (scan consts + carry —
 the carry is thereby counted ONCE across the whole loop body, not once per
@@ -67,7 +67,7 @@ from repro.roofline.analysis import UnknownDtypeError, dtype_bytes
 _MIB = float(2**20)
 
 # call-like primitives whose sub-jaxpr params key is one of the usual three
-_CALL_PRIMS = ("pjit", "closed_call", "core_call", "remat_call", "remat",
+_CALL_PRIMS = ("jit", "closed_call", "core_call", "remat_call", "remat",
                "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
                "checkpoint")
 

@@ -8,10 +8,10 @@ with two structural rules over explicit SPMD regions:
       one declared data-parallel axis, and an output declared *replicated*
       over a dp axis that shards an input must actually have been reduced
       over that axis by some collective. shard_map's own replication check
-      (``check_rep=True``) proves the latter natively — so the rule only
+      (``check_vma=True``) proves the latter natively — so the rule only
       fires where that guard was turned off, which is exactly how the
       classic lost-psum ships: per-host losses declared replicated,
-      ``check_rep=False`` silencing the one check that would have caught
+      ``check_vma=False`` silencing the one check that would have caught
       it, every host quietly training on a different objective.
 
   QL306 unconstrained collective in a donated scan body
@@ -52,11 +52,13 @@ def _eqns_in(jaxpr) -> Iterable[Any]:
         yield from j.eqns
 
 
-def _names_axes(names) -> Set[str]:
-    """All mesh axes mentioned by one shard_map in_names/out_names entry."""
+def _spec_axes(spec) -> Set[str]:
+    """All mesh axes mentioned by one shard_map in_specs/out_specs entry."""
     out: Set[str] = set()
-    for axes in names.values():
-        out.update(a for a in axes if isinstance(a, str))
+    for axes in spec:
+        if isinstance(axes, str):
+            axes = (axes,)
+        out.update(a for a in axes or () if isinstance(a, str))
     return out
 
 
@@ -74,7 +76,7 @@ def check_shard_map(entry: TracedEntry) -> Report:
             coll_axes: Set[str] = set()
             for _, axes in colls:
                 coll_axes.update(axes)
-            check_rep = bool(eqn.params.get("check_rep", True))
+            check_vma = bool(eqn.params.get("check_vma", True))
 
             if dp:
                 for e, axes in colls:
@@ -89,12 +91,12 @@ def check_shard_map(entry: TracedEntry) -> Report:
                             "cross-replica reduction this entry promises "
                             "is running on the wrong axis")
 
-            if not check_rep:
+            if not check_vma:
                 in_axes: Set[str] = set()
-                for names in eqn.params.get("in_names", ()):
-                    in_axes |= _names_axes(names)
-                for i, names in enumerate(eqn.params.get("out_names", ())):
-                    out_axes = _names_axes(names)
+                for spec in eqn.params["in_specs"]:
+                    in_axes |= _spec_axes(spec)
+                for i, spec in enumerate(eqn.params["out_specs"]):
+                    out_axes = _spec_axes(spec)
                     missing = sorted((in_axes - out_axes)
                                      & (dp or in_axes) - coll_axes)
                     if missing:
@@ -104,7 +106,7 @@ def check_shard_map(entry: TracedEntry) -> Report:
                             f"output {i} is declared replicated over mesh "
                             f"axes {missing} that shard an input, but no "
                             "collective reduces over them and "
-                            "check_rep=False disabled shard_map's own "
+                            "check_vma=False disabled shard_map's own "
                             "replication proof — each shard returns a "
                             "different value (lost psum)")
     return rep

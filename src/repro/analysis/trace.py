@@ -660,26 +660,23 @@ def _one_device_mesh():
 
 def lost_psum_entry(mesh=None) -> TracedEntry:
     """Seeded QL305 fixture: a sharded loss reduction whose psum runs over
-    the *model* axis instead of the data axis, with ``check_rep=False``
+    the *model* axis instead of the data axis, with ``check_vma=False``
     silencing shard_map's own replication check — the per-host loss is
     declared replicated but never actually reduced over data parallelism,
     so every host trains on a different objective (the classic lost psum).
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.optim.compress import get_shard_map
-
     mesh = mesh or _one_device_mesh()
-    shard_map = get_shard_map()
 
     def local_loss(x, y):
         err = jnp.mean((x - y) ** 2)
         # BUG (seeded): reduces over "model", leaving "data" unreduced
         return jax.lax.psum(err, "model")
 
-    fn = shard_map(local_loss, mesh=mesh,
-                   in_specs=(P("data"), P("data")),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(local_loss, mesh=mesh,
+                       in_specs=(P("data"), P("data")),
+                       out_specs=P(), check_vma=False)
     x = jnp.ones((8, 16), jnp.float32)
     y = jnp.ones((8, 16), jnp.float32)
     return trace_jitted(jax.jit(fn), (x, y),

@@ -52,7 +52,11 @@ the scanned step, and replicates the rounding/Adam/LSQ carry states and the
 minibatch schedule (``NamedSharding(mesh, P())``). The loss/MSE reductions
 are means over the *global* batch, so under jit the rounding-state gradients
 all-reduce (psum) over the data axes automatically and every device steps
-identical replicated states. The mesh is part of the engine cache key:
+identical replicated states. Where the deploy kernels are Pallas (on TPU),
+the student stream is ``shard_map``-ped over the same stream spec, because
+Pallas kernels cannot be partitioned automatically; expert (MoE) blocks are
+refused there, since their token routing spans samples. The mesh is part
+of the engine cache key:
 blocks still compile once per ``apply_key``, and the sharded trajectory
 reproduces the unsharded one (both pinned in tests/test_sharded_recon.py).
 ``sample_weight`` consumes ``data/pipeline.assemble_global_batch``'s loss
@@ -488,9 +492,34 @@ def _build_engine(block: BlockHandle, recipe: QuantRecipe,
 
     def student(params, x, astates):
         _STATS.student_compiles += 1
-        ctx = QuantCtx(mode="deploy", recipe=recipe, astates=astates,
-                       plans=plans_c)
-        return apply_c(params, x, ctx)
+
+        def run(params, x, astates):
+            ctx = QuantCtx(mode="deploy", recipe=recipe, astates=astates,
+                           plans=plans_c)
+            return apply_c(params, x, ctx)
+
+        from repro.kernels import ops as kops
+        if mesh is None or kops.resolve_backend()[0] != "pallas":
+            return run(params, x, astates)
+        # the partitioner cannot split a Pallas kernel, so each device runs
+        # the block on its own shard of the stream, with the weights and
+        # activation states replicated. That equals the unsharded block
+        # only if every sample's output depends on that sample alone, which
+        # expert (MoE) blocks break: they route tokens in groups formed
+        # over the whole batch. check_vma is off because Pallas outputs
+        # carry no varying-axes type.
+        if any(s.batch_dims for s in block.sites.values()):
+            raise NotImplementedError(
+                f"{block.name}: data-parallel PTQ of an expert block on "
+                "Pallas deploy kernels would route tokens per shard, not "
+                "over the whole batch; run it with mesh=None")
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.sharding import stream_spec
+        spec = stream_spec(x.shape[0], mesh)
+        return jax.shard_map(run, mesh=mesh, in_specs=(P(), spec, P()),
+                             out_specs=spec, check_vma=False)(params, x,
+                                                              astates)
 
     def recon_err(params, wstates, astates, x_q, y_fp):
         _STATS.recon_error_compiles += 1
@@ -602,7 +631,9 @@ def _run_scan(block: BlockHandle, recipe: QuantRecipe,
         # added compiles). sync= folds device completion into the span so
         # per-chunk time is honest, matching the block_until_ready below.
         with TELEMETRY.span("recon.chunk", block=block.name, start=it,
-                            steps=sl.stop - it) as sp, \
+                            steps=sl.stop - it,
+                            stream=str(getattr(getattr(x_q, "sharding", None),
+                                               "spec", None))) as sp, \
                 profiler.annotate("recon.chunk", n_chunk):
             c_w, c_a, wopt, aopt, lo, ms = eng.run_chunk(
                 block.params, c_w, c_a, wopt, aopt, x_q, y_fp,

@@ -26,10 +26,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.envelope import assert_grid_divisible
 
 
+def _codes_f32(codes):
+    """uint8 codes -> float32, widened through int32: Mosaic has no direct
+    uint8 -> float32 cast, and every code value is exact in both."""
+    return codes.astype(jnp.int32).astype(jnp.float32)
+
+
 def _unpack_f32(codes):
     """(bk//2, bn) packed uint8 -> (bk, bn) float32 codes, pairs along K."""
-    lo = (codes & 0xF).astype(jnp.float32)
-    hi = ((codes >> 4) & 0xF).astype(jnp.float32)
+    c = codes.astype(jnp.int32)
+    lo = (c & 0xF).astype(jnp.float32)
+    hi = ((c >> 4) & 0xF).astype(jnp.float32)
     bk2, bn = codes.shape
     return jnp.stack([lo, hi], axis=1).reshape(bk2 * 2, bn)
 
@@ -43,7 +50,7 @@ def _kernel(x_ref, c_ref, scale_ref, zero_ref, o_ref, acc_ref, *, k_steps,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     codes = c_ref[...]  # (bk//2, bn) uint8 if packed else (bk, bn)
-    q = _unpack_f32(codes) if packed else codes.astype(jnp.float32)
+    q = _unpack_f32(codes) if packed else _codes_f32(codes)
     w = scale_ref[...] * (q - zero_ref[...])  # dequant in VMEM
     x = x_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
@@ -63,7 +70,7 @@ def _kernel_batched(x_ref, c_ref, scale_ref, zero_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     codes = c_ref[0]  # expert-sliced block: (bk//2, bn) or (bk, bn)
-    q = _unpack_f32(codes) if packed else codes.astype(jnp.float32)
+    q = _unpack_f32(codes) if packed else _codes_f32(codes)
     w = scale_ref[0] * (q - zero_ref[0])
     x = x_ref[0].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(

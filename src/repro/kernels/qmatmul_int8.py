@@ -36,10 +36,11 @@ def _kernel(a_ref, b_ref, ascale_ref, azero_ref, bscale_ref, bzero_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    b = b_ref[...].astype(jnp.int32)
+    # int8 operands straight into the MXU (Mosaic rejects an int32 x int32
+    # dot); the int32 accumulation is exact either way
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == k_steps - 1)
     def _finish():

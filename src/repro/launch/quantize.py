@@ -51,6 +51,7 @@ from repro.core.reconstruct import (DEFAULT_CHUNK, engine_stats,
                                     quantize_blocks, reset_engine_stats,
                                     site_plans)
 from repro.data import CalibrationSet, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import (axis_size, dp_axes, make_debug_mesh,
                                make_production_mesh)
 from repro.models import build_model
@@ -147,6 +148,7 @@ def main():
                          "perfetto-loadable trace dir under --telemetry DIR "
                          "(or /tmp/repro_profile)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
@@ -371,9 +373,10 @@ def serve_smoke(model, qparams, astates, recipe, cfg, *, backend: str = "auto",
 
     ok, reason = serve_capability(model)
     if not ok:
-        # machine-readable skip (same contract as the serve bench row)
-        print(f"serve-smoke: skipped arch={cfg.name} reason={reason}")
-        return float("nan")
+        # the user asked for serving: a skip is a failure of this run (the
+        # reason string is the machine-readable one the bench rows carry)
+        raise SystemExit(f"serve-smoke: skipped arch={cfg.name} "
+                         f"reason={reason}")
     ctx = QuantCtx(mode="deploy", recipe=recipe, astates=astates,
                    backend=backend)
     tokens = jax.random.randint(jax.random.key(0), (batch, prompt_len), 0,
@@ -408,7 +411,7 @@ def serve_engine_run(model, qparams, astates, recipe, cfg, *,
     prefill, slot decode with the int8 KV cache by default. Prints sustained
     tokens/s at full occupancy, HBM per slot, per-bucket prefill latency
     (p50 over the run, not just the last call), per-request TTFT/queue-wait
-    percentiles, and the (flat) compile count. Degrades with a
+    percentiles, and the (flat) compile count. Exits non-zero with the
     machine-readable skip reason on families the slot layout cannot
     serve."""
     import numpy as np
@@ -420,8 +423,7 @@ def serve_engine_run(model, qparams, astates, recipe, cfg, *,
 
     ok, reason = serve_capability(model, engine=True, kv_quant=kv_quant)
     if not ok:
-        print(f"serve: skipped arch={cfg.name} reason={reason}")
-        return None
+        raise SystemExit(f"serve: skipped arch={cfg.name} reason={reason}")
     ctx = QuantCtx(mode="deploy", recipe=recipe, astates=astates,
                    backend=backend)
     max_len = max(32, 2 * max_new)

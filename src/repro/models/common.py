@@ -31,20 +31,24 @@ def stack_layers(layers):
     return list(layers)
 
 
-def scan_layers(body, carry, layers, *aux):
+def scan_layers(body, carry, layers, *aux, names=None):
     """``lax.scan`` body over per-layer params, with a mixed-precision path.
 
     ``body(carry, p_l) -> (carry, out)`` — or ``body(carry, (p_l, *aux_l))``
     when ``aux`` (stacked (L, ...) arrays sliced per layer) is given. When
     ``layers`` is a stacked pytree this is exactly ``lax.scan``; when it is a
     list of heterogeneous per-layer trees the loop unrolls eagerly (bigger
-    HLO, same math).
+    HLO, same math). ``names`` (one per layer) reaches the unrolled body as
+    ``name=``: each layer's sites then carry their canonical
+    ``layers.<i>.<site>`` names, so per-layer recipe rules and activation
+    states resolve at deploy time exactly as they did during PTQ.
     """
     if isinstance(layers, (list, tuple)):
         outs = []
         for i, p_l in enumerate(layers):
             aux_l = tuple(jax.tree.map(lambda a: a[i], a_) for a_ in aux)
-            carry, out = body(carry, (p_l, *aux_l) if aux else p_l)
+            kw = {} if names is None else {"name": names[i]}
+            carry, out = body(carry, (p_l, *aux_l) if aux else p_l, **kw)
             outs.append(out)
         if not outs or all(o is None for o in outs):
             return carry, None

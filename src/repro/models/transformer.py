@@ -41,6 +41,16 @@ def _cache_write(buf, li, pos, val):
         buf, val[None], (li, 0, pos) + (0,) * (buf.ndim - 3))
 
 
+def _layer_names(layers, layer_off: int):
+    """Canonical per-layer site prefixes (``layers.<i>``, global index — the
+    names ``quant_blocks`` gave the sites) for an unrolled mixed-precision
+    layer list; a stacked scan shares one traced body, so it keeps the
+    layer-agnostic prefix."""
+    if not isinstance(layers, (list, tuple)):
+        return None
+    return [f"layers.{layer_off + i}" for i in range(len(layers))]
+
+
 # ----------------------------------------------------------------- params
 def _attn_params(key, cfg, dtype) -> dict:
     if cfg.use_mla:
@@ -154,19 +164,22 @@ class TransformerLM:
         return x, aux, kv
 
     def _scan_layers(self, stacked, x, ctx, sin, cos, kind, name,
-                     collect_kv=False):
+                     collect_kv=False, layer_off=0):
         cfg = self.cfg
 
-        def body(carry, p_l):
-            h, aux = carry
-            y, a, kv = self.layer_apply(p_l, h, ctx, name, sin, cos, kind)
-            out = kv if collect_kv else None
-            return (y, aux + a), out
+        def body(carry, p_l, name=name):
+            def f(carry, p_l):
+                h, aux = carry
+                y, a, kv = self.layer_apply(p_l, h, ctx, name, sin, cos, kind)
+                return (y, aux + a), (kv if collect_kv else None)
+            if cfg.remat:
+                f = jax.checkpoint(
+                    f, policy=jax.checkpoint_policies.nothing_saveable)
+            return f(carry, p_l)
 
-        if cfg.remat:
-            body = jax.checkpoint(body,
-                                  policy=jax.checkpoint_policies.nothing_saveable)
-        (x, aux), kvs = common.scan_layers(body, (x, jnp.float32(0.0)), stacked)
+        (x, aux), kvs = common.scan_layers(
+            body, (x, jnp.float32(0.0)), stacked,
+            names=_layer_names(stacked, layer_off))
         return x, aux, kvs
 
     # ----------------------------------------------------------- forward
@@ -191,7 +204,9 @@ class TransformerLM:
             kvs.append(kv)
         kind = "moe" if cfg.is_moe else "dense"
         x, a, kv = self._scan_layers(params["layers"], x, ctx, sin, cos, kind,
-                                     "layers", collect_kv)
+                                     "layers", collect_kv,
+                                     layer_off=cfg.first_dense
+                                     if "dense_layers" in params else 0)
         aux += a
         kvs.append(kv)
         x = common.apply_norm(cfg.norm, x, params.get("final_norm"))
@@ -360,18 +375,18 @@ class TransformerLM:
                      sin, cos):
         cfg = self.cfg
 
-        def body(carry, inp):
+        def body(carry, inp, name="layers"):
             h, cache = carry
             p_l, i = inp
             li = layer_off + i
             z = common.apply_norm(cfg.norm, h, p_l.get("ln1"))
             if cfg.use_mla:
-                ckv, kr = mla._kv_latent(p_l["attn"], z, cfg, ctx, "layers",
+                ckv, kr = mla._kv_latent(p_l["attn"], z, cfg, ctx, name,
                                          sin, cos)
                 cache["ckv"] = _cache_write(cache["ckv"], li, pos, ckv)
                 cache["kr"] = _cache_write(cache["kr"], li, pos, kr)
                 a_out = mla.mla_decode(
-                    p_l["attn"], z, cfg, ctx, "layers", sin, cos,
+                    p_l["attn"], z, cfg, ctx, name, sin, cos,
                     jax.lax.dynamic_index_in_dim(cache["ckv"], li, 0, False),
                     jax.lax.dynamic_index_in_dim(cache["kr"], li, 0, False),
                     pos)
@@ -379,11 +394,11 @@ class TransformerLM:
                 B = z.shape[0]
                 H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
                 a = p_l["attn"]
-                q = ctx.linear("layers.wq", z, a["wq"], a.get("bq")).reshape(
+                q = ctx.linear(f"{name}.wq", z, a["wq"], a.get("bq")).reshape(
                     B, 1, H, Dh)
-                k = ctx.linear("layers.wk", z, a["wk"], a.get("bk")).reshape(
+                k = ctx.linear(f"{name}.wk", z, a["wk"], a.get("bk")).reshape(
                     B, 1, Hkv, Dh)
-                v = ctx.linear("layers.wv", z, a["wv"], a.get("bv")).reshape(
+                v = ctx.linear(f"{name}.wv", z, a["wv"], a.get("bv")).reshape(
                     B, 1, Hkv, Dh)
                 q = common.apply_rope(q, sin, cos)
                 k = common.apply_rope(k, sin, cos)
@@ -409,19 +424,20 @@ class TransformerLM:
                                                        False)
                     o = attn.decode_attention(q, k_l, v_l, pos,
                                               window=cfg.local_window)
-                a_out = ctx.linear("layers.wo", o.reshape(B, 1, H * Dh),
+                a_out = ctx.linear(f"{name}.wo", o.reshape(B, 1, H * Dh),
                                    a["wo"])
             h = h + a_out * cfg.resid_mult
             z = common.apply_norm(cfg.norm, h, p_l.get("ln2"))
             if kind == "moe":
-                m_out, _ = moe.moe_ffn(p_l["mlp"], z, cfg, ctx, "layers")
+                m_out, _ = moe.moe_ffn(p_l["mlp"], z, cfg, ctx, name)
             else:
-                m_out = common.mlp(p_l["mlp"], z, ctx, "layers.mlp", cfg.act)
+                m_out = common.mlp(p_l["mlp"], z, ctx, f"{name}.mlp", cfg.act)
             h = h + m_out * cfg.resid_mult
             return (h, cache), None
 
         (x, cache), _ = common.scan_layers(body, (x, cache), stack,
-                                           jnp.arange(n))
+                                           jnp.arange(n),
+                                           names=_layer_names(stack, layer_off))
         return x, cache
 
     # --------------------------------------------------------- PTQ plan

@@ -20,27 +20,6 @@ import jax.numpy as jnp
 from repro.optim.adam import _dq8, _q8
 
 
-def get_shard_map():
-    """Version-compatible ``shard_map``: top-level ``jax.shard_map`` on newer
-    jax, ``jax.experimental.shard_map.shard_map`` on older releases. Single
-    accessor for every caller that wraps :func:`compressed_psum` (tests, the
-    pretraining all-reduce)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def shard_mapped_psum(fn, mesh, in_specs, out_specs):
-    """``shard_map``-wrap ``fn`` (which calls :func:`compressed_psum`
-    internally) over ``mesh`` — convenience wrapper for callers of the
-    compressed all-reduce (currently the substrate tests; a data-parallel
-    training loop would enter here)."""
-    return get_shard_map()(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs)
-
-
 def compress_tree(grads: Any) -> Any:
     """int8-encode every leaf (block absmax)."""
     return jax.tree.map(lambda g: dict(zip(("q", "s"), _q8(g))), grads)

@@ -22,7 +22,7 @@ one path (e.g. the per-site minibatch keys of layer-wise recon) does not
 silently refresh the oracles for untouched paths.
 
 Recording history of intended trajectory changes since the legacy capture:
-  - partitionable threefry (repro/__init__.py): sharding-invariant RNG is a
+  - partitionable threefry (jax's default since 0.5): sharding-invariant RNG is a
     hard requirement for data-parallel calibration (the legacy stream draws
     *different* QDrop masks when outputs are sharded), and it changes every
     random stream — all tags re-recorded.

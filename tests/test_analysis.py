@@ -149,7 +149,7 @@ def test_donation_clean_on_dealiased_chunk():
 
 # ------------------------------------------------- QL204/QL206 negative
 def test_f64_promotion_detected():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         f = jax.jit(lambda x: jnp.asarray(x, jnp.float64) * 2.0)
         entry = trace.trace_jitted(f, (jnp.ones((4,), jnp.float32),),
                                    name="f64", argnames=("x",))

@@ -20,7 +20,7 @@ from repro.core import QuantRecipe
 from repro.core import reconstruct as rec
 from repro.core.context import QuantCtx
 from repro.core.reconstruct import quantize_blocks, reconstruct_block
-from repro.launch.mesh import dp_axes, make_debug_mesh
+from repro.launch.mesh import dp_axes, make_debug_mesh, make_flat_mesh
 from repro.launch.sharding import stream_spec
 
 from test_recon_engine import (FIXTURE, assert_matches_fixture, make_block,
@@ -30,7 +30,7 @@ RTOL = 2e-3
 
 
 def _single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_flat_mesh(1)
 
 
 def _debug_mesh_or_skip():
@@ -133,6 +133,92 @@ def test_stream_spec_degrades_on_uneven_sample_counts():
         dmesh = make_debug_mesh()
         assert stream_spec(48, dmesh) == P(("data",))
         assert stream_spec(5, dmesh) == P()  # uneven -> replicated
+
+
+# ------------------------------------------- Pallas deploy kernels per shard
+def _lm_blocks(arch, n=8, seq=16, **cfg_changes):
+    import dataclasses
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+    cfg = dataclasses.replace(get_smoke_config(arch), **cfg_changes)
+    model = build_model(cfg)
+    tokens = jax.random.randint(jax.random.key(1), (n, seq), 0, cfg.vocab)
+    return model.quant_blocks(model.init(jax.random.key(0)), tokens)
+
+
+def _force_pallas(monkeypatch):
+    """Deploy matmuls on Pallas kernels (interpreted here), as
+    ``resolve_backend`` picks compiled ones on a TPU."""
+    import repro.kernels.ops as kops
+    monkeypatch.setattr(kops, "resolve_backend",
+                        lambda backend="auto", interpret=None: ("pallas",
+                                                                True))
+
+
+def _student(block, recipe, x0, mesh):
+    canon = rec._canon_names(block)
+    plans = rec.site_plans(block, recipe)
+    astates = rec.init_astates(block, recipe, x0)
+    qparams = rec.finalize_block(block, recipe,
+                                 rec.init_wstates(block, recipe))
+    eng = rec._build_engine(block, recipe,
+                            {canon[r]: plans[r] for r in block.sites}, canon,
+                            mesh)
+    a_c = {canon[r]: astates[r] for r in block.sites}
+    return eng.student, (qparams, x0, a_c)
+
+
+def test_pallas_student_runs_per_shard(monkeypatch):
+    """On Pallas deploy kernels the data-parallel student runs under
+    shard_map (the partitioner cannot split a Pallas kernel) and gives the
+    unsharded student's output."""
+    _force_pallas(monkeypatch)
+    x0, blocks, _ = _lm_blocks("smollm-135m")
+    recipe = QuantRecipe(method="flexround", w_bits=4, a_bits=8, iters=1,
+                         batch_size=4, rules=("layers.0.wq:w_bits=8",))
+    mesh = _single_device_mesh()
+    f_sh, args = _student(blocks[0], recipe, x0, mesh)
+    f_1, _ = _student(blocks[0], recipe, x0, None)
+    assert "shard_map" in str(jax.make_jaxpr(f_sh)(*args))
+    assert "shard_map" not in str(jax.make_jaxpr(f_1)(*args))
+    np.testing.assert_allclose(np.asarray(f_sh(*args)),
+                               np.asarray(f_1(*args)), rtol=1e-6, atol=1e-6)
+
+
+def test_pallas_student_refuses_expert_blocks(monkeypatch):
+    """Per-shard MoE routing would group tokens differently from the
+    unsharded run, so the Pallas per-shard student refuses expert blocks."""
+    _force_pallas(monkeypatch)
+    x0, blocks, _ = _lm_blocks("llama4-scout-17b-a16e")
+    block = blocks[-1]
+    assert any(s.batch_dims for s in block.sites.values())
+    recipe = QuantRecipe(method="flexround", w_bits=4, iters=1, batch_size=4)
+    f, args = _student(block, recipe, x0, _single_device_mesh())
+    with pytest.raises(NotImplementedError, match="expert block"):
+        f(*args)
+
+
+def test_moe_sharded_student_with_small_shards_matches_unsharded():
+    """MoE routing groups span samples: with 32 tokens per shard, under the
+    64-token moe_group, and capacity drops on, the data-parallel student
+    (partitioned by XLA, not run per shard) routes as the unsharded one."""
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices (XLA_FLAGS="
+                    "--xla_force_host_platform_device_count=4)")
+    x0, blocks, _ = _lm_blocks("llama4-scout-17b-a16e", capacity_factor=1.0)
+    assert x0.shape[0] * x0.shape[1] // 4 < 64
+    recipe = QuantRecipe(method="flexround", w_bits=4, a_bits=8, iters=1,
+                         batch_size=4)
+    mesh = make_flat_mesh(4)
+    f_sh, (qp, x, a_c) = _student(blocks[-1], recipe, x0, mesh)
+    f_1, _ = _student(blocks[-1], recipe, x0, None)
+    from repro.launch.sharding import replicated, stream_sharding
+    x_sh = jax.device_put(x, stream_sharding(mesh, x.shape[0]))
+    qp, a_c = jax.device_put((qp, a_c), replicated(mesh))
+    np.testing.assert_allclose(np.asarray(f_sh(qp, x_sh, a_c)),
+                               np.asarray(f_1(qp, x, a_c)), rtol=1e-5,
+                               atol=1e-5)
 
 
 # ------------------------------------------------------ debug-mesh (8 dev)

@@ -176,7 +176,6 @@ def test_compressed_psum_shard_map():
     """Compressed all-reduce under shard_map == mean of shards (±int8 err)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.optim.compress import shard_mapped_psum
     if jax.device_count() < 2:
         pytest.skip("needs >=2 devices")
     mesh = jax.make_mesh((jax.device_count(),), ("d",))
@@ -186,7 +185,8 @@ def test_compressed_psum_shard_map():
         red, _ = compressed_psum({"g": gs[0]}, "d")
         return red["g"][None]
 
-    out = shard_mapped_psum(f, mesh, P("d", None), P("d", None))(g)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P("d", None),
+                        out_specs=P("d", None))(g)
     want = jnp.mean(g, axis=0)
     for i in range(jax.device_count()):
         np.testing.assert_allclose(np.asarray(out[i]), np.asarray(want),
