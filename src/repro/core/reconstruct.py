@@ -83,7 +83,6 @@ from repro.core import lsq
 from repro.core import paths as pth
 from repro.core.context import QuantCtx
 from repro.core.quant_config import QuantRecipe, SitePlan
-from repro.obs import profiler
 from repro.obs.telemetry import TELEMETRY, Stopwatch
 from repro.optim.adam import AdamConfig, adam_init, adam_update
 
@@ -623,7 +622,7 @@ def _run_scan(block: BlockHandle, recipe: QuantRecipe,
     chunk = max(1, min(chunk, recipe.iters))
     sw = Stopwatch()
     losses, mses = [], []
-    it, n_chunk = 0, 0
+    it = 0
     while it < recipe.iters:
         sl = slice(it, it + min(chunk, recipe.iters - it))
         # host-side span around the compiled dispatch: the traced run_chunk
@@ -633,8 +632,7 @@ def _run_scan(block: BlockHandle, recipe: QuantRecipe,
         with TELEMETRY.span("recon.chunk", block=block.name, start=it,
                             steps=sl.stop - it,
                             stream=str(getattr(getattr(x_q, "sharding", None),
-                                               "spec", None))) as sp, \
-                profiler.annotate("recon.chunk", n_chunk):
+                                               "spec", None))) as sp:
             c_w, c_a, wopt, aopt, lo, ms = eng.run_chunk(
                 block.params, c_w, c_a, wopt, aopt, x_q, y_fp,
                 None if idx is None else idx[sl], k2s[sl], steps[sl], salts,
@@ -643,7 +641,6 @@ def _run_scan(block: BlockHandle, recipe: QuantRecipe,
         losses.append(lo)
         mses.append(ms)
         it = sl.stop
-        n_chunk += 1
     if mses:
         jax.block_until_ready(mses[-1])
     loop_s = sw.elapsed_s()

@@ -146,7 +146,8 @@ def main():
     ap.add_argument("--profile", action="store_true",
                     help="wrap the run in jax.profiler.trace, emitting a "
                          "perfetto-loadable trace dir under --telemetry DIR "
-                         "(or /tmp/repro_profile)")
+                         "(or /tmp/repro_profile); telemetry spans are on "
+                         "while it runs, so they land in the trace")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -185,6 +186,8 @@ def main():
               f"(git {manifest.git_sha}, schema {manifest.schema_version})")
     if args.profile:
         from repro.obs import profiler
+        if not TELEMETRY.enabled:
+            TELEMETRY.enable()  # spans without a sink: the trace holds them
         profiler.start(os.path.join(args.telemetry or "/tmp/repro_profile",
                                     "profile"))
 

@@ -2,7 +2,8 @@
 
 Layout:
   telemetry.py       process-global counters/gauges/histograms + nested
-                     host-side spans (disabled by default, zero-cost off)
+                     host-side spans, each also a profiler TraceAnnotation
+                     (disabled by default, zero-cost off)
   sink.py            JSONL event sink + RunManifest (run identity stamped
                      into bench rows, checkpoint meta, serve stats)
   serve_metrics.py   per-request lifecycle metrics: queue wait, TTFT,

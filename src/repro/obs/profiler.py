@@ -1,10 +1,10 @@
 """``--profile`` wiring: a perfetto-loadable ``jax.profiler`` trace dir.
 
 One process-wide trace (jax allows a single active profile): ``start(dir)``
-/ ``stop()`` bracket the run, and the hot loops mark themselves with
-:func:`annotate` — ``jax.profiler.StepTraceAnnotation`` around each recon
-chunk and serve step, a no-op ``nullcontext`` while profiling is off, so
-instrumented loops pay nothing by default. Load the emitted directory in
+/ ``stop()`` bracket the run. The hot loops need no markers of their own:
+every enabled telemetry span writes a ``TraceAnnotation`` of its name
+(:mod:`repro.obs.telemetry`), so ``recon.chunk``, ``serve.decode_step`` and
+the rest land on the trace's host plane. Load the emitted directory in
 perfetto (ui.perfetto.dev) or TensorBoard's profile plugin.
 """
 from __future__ import annotations
@@ -62,13 +62,3 @@ def trace(trace_dir: str):
         if started:
             stop()
 
-
-def annotate(name: str, step: Optional[int] = None):
-    """Per-iteration marker inside an active trace (recon chunks, serve
-    steps). Free when profiling is off."""
-    if _ACTIVE_DIR is None:
-        return contextlib.nullcontext()
-    import jax
-    if step is None:
-        return jax.profiler.StepTraceAnnotation(name)
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)

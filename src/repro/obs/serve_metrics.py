@@ -10,15 +10,19 @@ the last call per bucket survived — and ``ServeEngine.stats()`` /
 When the global :data:`repro.obs.telemetry.TELEMETRY` is enabled, each
 admitted request additionally emits one ``kind="request"`` JSONL event
 (rid, queue_wait_us, ttft_us, bucket) and the occupancy/backlog gauges are
-mirrored — the serve benchmark derives its per-request percentile rows
-from exactly those sink records.
+mirrored — the toy ``serve/requests/*`` rows of ``benchmarks/tables.py``
+fold those sink records back into percentiles. Prefill and decode-step
+times reach the sink as the engine's ``serve.prefill`` /
+``serve.decode_step`` spans, not as histograms here.
 
 Request lifecycle and where each metric is measured::
 
-    submit ──queue_wait──> admit(prefill) ──> first token   [ttft ends here]
-                                └─> decode steps ... finish
+    arrival ──queue_wait──> admit(prefill) ──> first token  [ttft ends here]
+                                 └─> decode steps ... finish
 
-``ttft`` spans submit → end of the admitting prefill call (the prefill
+``arrival`` is the request's own (``Request.arrival``, e.g. an open
+loop's due time) where the caller gives one, else the submit time.
+``ttft`` spans arrival → end of the admitting prefill call (the prefill
 logits already yield token #1, so first-token latency *is* prefill exit).
 """
 from __future__ import annotations
@@ -54,13 +58,10 @@ class ServeMetrics:
 
     def observe_prefill(self, bucket: int, us: float) -> None:
         self.prefill_hist(bucket).observe(us)
-        if self.tel.enabled:
-            self.tel.histogram(f"serve.prefill_us.b{bucket}").observe(us)
 
     def observe_decode(self, us: float, tokens: int) -> None:
         self.decode_step_us.observe(us)
         if self.tel.enabled:
-            self.tel.histogram("serve.decode_step_us").observe(us)
             self.tel.counter("serve.tokens_emitted").inc(tokens)
 
     def set_occupancy(self, n: int) -> None:
@@ -74,8 +75,10 @@ class ServeMetrics:
             self.tel.gauge("serve.backlog_depth").set(n)
 
     # ---------------------------------------------------------- scheduler
-    def on_submit(self, rid: int) -> None:
-        self._submit_t[rid] = _now()
+    def on_submit(self, rid: int, arrival: Optional[float] = None) -> None:
+        """Start the request's clock at ``arrival`` (``obs.now`` clock)
+        when the caller knows it, else now."""
+        self._submit_t[rid] = _now() if arrival is None else arrival
 
     def on_admitted(self, rid: int, bucket: int, admit_start: float,
                     first_token_t: float) -> None:
@@ -125,8 +128,9 @@ class ServeMetrics:
 def percentiles_from_events(records, kind: str, field: str,
                             ) -> Optional[Dict[str, float]]:
     """Fold sink records (``kind`` match) into a percentile summary of one
-    field — how the serve benchmark turns raw ``kind="request"`` JSONL
-    events back into TTFT / queue-wait percentile rows."""
+    field — how the toy ``serve/requests/*`` rows of ``benchmarks/tables.py``
+    turn raw ``kind="request"`` JSONL events back into TTFT / queue-wait
+    percentile rows."""
     h = Histogram(f"{kind}.{field}")
     for rec in records:
         if rec.get("kind") == kind and field in rec:

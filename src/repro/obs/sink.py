@@ -30,7 +30,13 @@ import os
 import subprocess
 from typing import Any, Dict, List, Optional
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
+
+# Version 2: span records carry ``id``, ``parent_id`` (None at a thread's
+# top level) and ``t0_ns`` (start on ``telemetry.now_ns``'s clock), which
+# place a span on the profiler trace's clock and give a layer's self time.
+_SPAN_FIELDS_V2 = {"name": (str,), "id": (int,), "parent_id": (int, type(None)),
+                   "t0_ns": (int,), "dur_us": (int, float)}
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
@@ -160,8 +166,10 @@ def current_manifest() -> RunManifest:
 # ----------------------------------------------------------------- validation
 def validate_events(path: str) -> List[str]:
     """Schema-check a telemetry JSONL: every line parses, carries ``kind`` +
-    a ``schema`` no newer than this reader, and at least one manifest record
-    with a git sha is present. Returns a list of errors (empty = valid)."""
+    a ``schema`` no newer than this reader, a span record of schema 2 or
+    later carries its id, parent id, start and duration, and at least one
+    manifest record with a git sha is present. Returns a list of errors
+    (empty = valid)."""
     errors: List[str] = []
     n, manifests = 0, 0
     try:
@@ -187,6 +195,12 @@ def validate_events(path: str) -> List[str]:
             elif schema > SCHEMA_VERSION:
                 errors.append(f"{path}:{i}: schema {schema} is newer than "
                               f"this reader ({SCHEMA_VERSION})")
+            if (rec.get("kind") == "span" and isinstance(schema, int)
+                    and schema >= 2):
+                for k, types in _SPAN_FIELDS_V2.items():
+                    if k not in rec or type(rec[k]) not in types:
+                        errors.append(f"{path}:{i}: span record has no "
+                                      f"valid {k!r}")
             if rec.get("kind") == "manifest":
                 manifests += 1
                 if not rec.get("git_sha"):

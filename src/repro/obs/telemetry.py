@@ -14,6 +14,14 @@ Design contract (the part quantlint enforces, see ROADMAP "Observability"):
   attribute bumps. The default state is disabled — enabling requires an
   explicit ``TELEMETRY.enable(...)`` (``launch/quantize --telemetry DIR``).
 
+* **One span system, on the profiler's clock.** While enabled, every span
+  also enters a ``jax.profiler.TraceAnnotation`` of its own name with its
+  ``span_id`` as a stat, so an active profiler trace holds the program's
+  spans on its host plane beside the device's operations, and a sink
+  record matches its trace event by id. The annotation closes after the
+  span's ``block_on`` sync. Records carry ``id``, ``parent_id`` and
+  ``t0_ns`` (:func:`now_ns`), from which a layer's self time follows.
+
 * **Device work is attributed explicitly.** A span measures wall time; jax
   dispatch is async, so a span around a compiled call measures *dispatch*
   unless you opt in: ``sp.block_on(out)`` (or ``span(..., sync=out)``)
@@ -22,17 +30,25 @@ Design contract (the part quantlint enforces, see ROADMAP "Observability"):
   happens to block next.
 
 Span taxonomy (dotted, coarse-to-fine): ``recon.block > recon.chunk``,
-``alloc.teacher`` / ``alloc.probe``, ``serve.build``, ``serve.prefill``,
-``serve.decode_step``. XLA compiles are attributed to the innermost open
-span by :mod:`repro.obs.compile_events`.
+``alloc.teacher`` / ``alloc.probe``, ``serve.build``, ``serve.pump >
+serve.admit > serve.prefill > serve.prefill.{dispatch,wait}``,
+``serve.pump > serve.decode_step > serve.decode.{dispatch,wait}``,
+``serve.pump > serve.decode.bookkeep``, ``serve.emit``, ``serve.detok``
+(the detokenize thread) and ``obs.gc`` (Python's generation-1 and -2
+collections; generation 0 is counted in ``obs.gc0``). XLA compiles are
+attributed to the innermost open span by :mod:`repro.obs.compile_events`.
 """
 from __future__ import annotations
 
+import gc
+import itertools
 import math
 import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.sink import SCHEMA_VERSION, RunManifest
 
@@ -41,6 +57,11 @@ def now() -> float:
     """Monotonic host timestamp (seconds) — the sanctioned absolute clock
     for lifecycle timing (queue wait, TTFT) outside this module."""
     return time.perf_counter()
+
+
+def now_ns() -> int:
+    """:func:`now` in integer nanoseconds: span starts and queue stamps."""
+    return time.perf_counter_ns()
 
 
 class Stopwatch:
@@ -152,8 +173,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("name", "attrs", "parent", "depth", "dur_us",
-                 "_tel", "_sync", "_t0")
+    __slots__ = ("name", "attrs", "parent", "depth", "dur_us", "id",
+                 "parent_id", "t0_ns", "_tel", "_sync", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, sync: Any,
                  attrs: Dict[str, Any]):
@@ -161,7 +182,9 @@ class Span:
         self.name = name
         self.attrs = attrs
         self._sync = sync
+        self.id = next(tel._ids)
         self.parent: Optional[str] = None
+        self.parent_id: Optional[int] = None
         self.depth = 0
         self.dur_us = 0.0
 
@@ -175,17 +198,21 @@ class Span:
 
     def __enter__(self) -> "Span":
         stack = self._tel._stack()
-        self.parent = stack[-1].name if stack else None
+        if stack:
+            self.parent, self.parent_id = stack[-1].name, stack[-1].id
         self.depth = len(stack)
         stack.append(self)
-        self._t0 = time.perf_counter()
+        self._ann = TraceAnnotation(self.name, span_id=self.id)
+        self._ann.__enter__()
+        self.t0_ns = now_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         if self._sync is not None:
             import jax
             jax.block_until_ready(self._sync)
-        self.dur_us = (time.perf_counter() - self._t0) * 1e6
+        self.dur_us = (now_ns() - self.t0_ns) / 1e3
+        self._ann.__exit__(None, None, None)
         stack = self._tel._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -205,6 +232,7 @@ class Telemetry:
         self._sink = None
         self._lock = threading.Lock()
         self._tls = threading.local()
+        self._ids = itertools.count(1)
 
     # ------------------------------------------------------------- metrics
     def counter(self, name: str) -> Counter:
@@ -248,7 +276,8 @@ class Telemetry:
 
     def _record_span(self, span: Span, synced: bool) -> None:
         self.histogram(f"span.{span.name}").observe(span.dur_us)
-        rec = {"kind": "span", "name": span.name,
+        rec = {"kind": "span", "name": span.name, "id": span.id,
+               "parent_id": span.parent_id, "t0_ns": span.t0_ns,
                "dur_us": round(span.dur_us, 3), "depth": span.depth,
                "parent": span.parent, "synced": synced}
         if span.attrs:
@@ -257,10 +286,36 @@ class Telemetry:
 
     # ---------------------------------------------------------------- sink
     def emit(self, record: Dict[str, Any]) -> None:
-        if self._sink is not None:
+        sink = self._sink  # read once: another thread may disable
+        if sink is not None:
             record.setdefault("schema", SCHEMA_VERSION)
             record.setdefault("ts", time.time())
-            self._sink.emit(record)
+            sink.emit(record)
+
+    def _on_gc(self, phase: str, info: Dict[str, Any]) -> None:
+        """``gc.callbacks`` hook: generation 0 is counted, older
+        generations become ``obs.gc`` spans on the collecting thread."""
+        gen = info["generation"]
+        if gen == 0:
+            if phase == "start" and self.enabled:
+                self.counter("obs.gc0").inc()
+            return
+        if phase == "start":
+            sp = self.span("obs.gc", generation=gen)
+            if sp is not _NULL_SPAN:
+                self._tls.gc_span = sp.__enter__()
+        else:
+            sp = getattr(self._tls, "gc_span", None)
+            if sp is not None:
+                self._tls.gc_span = None
+                sp.annotate(collected=info["collected"])
+                sp.__exit__(None, None, None)
+
+    def _install_gc(self, on: bool) -> None:
+        if on and self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        elif not on and self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     def enable(self, sink=None, manifest: Optional[RunManifest] = None
                ) -> None:
@@ -271,12 +326,14 @@ class Telemetry:
             sink.emit(manifest.record())
         from repro.obs import compile_events
         compile_events.install()
+        self._install_gc(True)
 
     def disable(self) -> None:
         self.enabled = False
-        if self._sink is not None:
-            self._sink.close()
-        self._sink = None
+        self._install_gc(False)
+        sink, self._sink = self._sink, None  # detach before closing
+        if sink is not None:
+            sink.close()
 
     @contextmanager
     def enabled_scope(self, sink=None,
@@ -291,10 +348,12 @@ class Telemetry:
         self.manifest = manifest
         if manifest is not None and sink is not None:
             sink.emit(manifest.record())
+        self._install_gc(True)
         try:
             yield self
         finally:
             self.enabled, self._sink, self.manifest = prev
+            self._install_gc(self.enabled)
 
     # ------------------------------------------------------------ snapshot
     def snapshot(self) -> Dict[str, Any]:

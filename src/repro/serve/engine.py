@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import profiler
 from repro.obs.serve_metrics import ServeMetrics
 from repro.obs.sink import current_manifest
 from repro.obs.telemetry import TELEMETRY, Stopwatch
@@ -241,11 +240,16 @@ class ServeEngine:
         yield token #1, so a request costs ``1 prefill + (max_new - 1)``
         decode steps. One compiled call regardless of group fill.
         """
+        if not requests:
+            return []
+        with TELEMETRY.span("serve.admit",
+                            rids=[rid for rid, _, _ in requests]):
+            return self._admit(requests)
+
+    def _admit(self, requests):
         c = self.cfg
         G = c.prefill_group
         free = self.free_slots()
-        if not requests:
-            return []
         if len(requests) > min(G, len(free)):
             raise ValueError(f"admit got {len(requests)} requests for "
                              f"{len(free)} free slots, group {G}")
@@ -266,9 +270,12 @@ class ServeEngine:
         sw = Stopwatch()
         with TELEMETRY.span("serve.prefill", bucket=bucket,
                             group=len(requests)):
-            self.state, first = self._prefill_exec[bucket](
-                self.params, self.state, tokens, true_len, slot_ids, max_new)
-            first = np.asarray(first)  # host sync: first tokens are needed
+            with TELEMETRY.span("serve.prefill.dispatch"):
+                self.state, first = self._prefill_exec[bucket](
+                    self.params, self.state, tokens, true_len, slot_ids,
+                    max_new)
+            with TELEMETRY.span("serve.prefill.wait"):
+                first = np.asarray(first)  # host sync: first tokens needed
         self.metrics.observe_prefill(bucket, sw.elapsed_us())
         out = []
         for row, (rid, _, _) in enumerate(requests):
@@ -290,29 +297,33 @@ class ServeEngine:
         slots that were active. Frees slots whose budget is exhausted."""
         meta = {k: self.state[k] for k in ("tokens", "pos", "remaining")}
         sw = Stopwatch()
-        with TELEMETRY.span("serve.decode_step", active=self.active), \
-                profiler.annotate("serve.decode_step", self.decode_steps):
-            cache, meta, emitted = self._decode_exec(
-                self.params, self.state["cache"], meta)
+        with TELEMETRY.span("serve.decode_step") as sp:
+            if TELEMETRY.enabled:  # the slot scan is paid only when read
+                sp.annotate(active=self.active)
+            with TELEMETRY.span("serve.decode.dispatch"):
+                cache, meta, emitted = self._decode_exec(
+                    self.params, self.state["cache"], meta)
             self.state = {"cache": cache, **meta}
-            emitted = np.asarray(emitted)  # host sync: tokens are consumed
+            with TELEMETRY.span("serve.decode.wait"):
+                emitted = np.asarray(emitted)  # host sync: tokens consumed
         step_us = sw.elapsed_us()
         self.decode_steps += 1
-        out = []
-        for i, s in enumerate(self.slots):
-            if s.rid is None:
-                continue
-            tok = int(emitted[i])
-            s.emitted.append(tok)
-            s.remaining -= 1
-            self.tokens_emitted += 1
-            out.append((s.rid, tok))
-            if s.remaining <= 0:
-                self._finished.append((s.rid, s.emitted))
-                self.metrics.on_finished(s.rid)
-                self.slots[i] = SlotView()
-        self.metrics.observe_decode(step_us, len(out))
-        self.metrics.set_occupancy(self.active)
+        with TELEMETRY.span("serve.decode.bookkeep"):
+            out = []
+            for i, s in enumerate(self.slots):
+                if s.rid is None:
+                    continue
+                tok = int(emitted[i])
+                s.emitted.append(tok)
+                s.remaining -= 1
+                self.tokens_emitted += 1
+                out.append((s.rid, tok))
+                if s.remaining <= 0:
+                    self._finished.append((s.rid, s.emitted))
+                    self.metrics.on_finished(s.rid)
+                    self.slots[i] = SlotView()
+            self.metrics.observe_decode(step_us, len(out))
+            self.metrics.set_occupancy(self.active)
         return out
 
     @property

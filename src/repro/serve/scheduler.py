@@ -18,10 +18,16 @@ host, so everything host-flavored lives here:
   the first exception is recorded on the scheduler, counted as
   ``detok_errors`` in telemetry, and re-raised from ``pump()``/``run()``/
   ``close()`` — the loop keeps draining so ``queue.join()`` never hangs.
+  While telemetry is enabled, ``_emit`` stamps each put and the thread
+  records one ``serve.detok`` span per run of items it handles without
+  blocking, with the ``tokens`` it timed and their ``lag_us_sum``
+  (callback start minus put): how long a token waits between the engine
+  and the client.
 
-* **Request lifecycle metrics** — ``submit`` stamps the enqueue time, and
-  the admitting prefill closes the queue-wait (submit → prefill start)
-  and TTFT (submit → first token, which the prefill itself emits) windows
+* **Request lifecycle metrics** — ``submit`` stamps the request's
+  ``arrival`` (or the enqueue time, where the caller gives none), and
+  the admitting prefill closes the queue-wait (arrival → prefill start)
+  and TTFT (arrival → first token, which the prefill itself emits) windows
   on the engine's :class:`repro.obs.ServeMetrics`; backlog depth and slot
   occupancy are mirrored as gauges. Drain via ``Scheduler.stats()``.
 
@@ -38,7 +44,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro.obs.serve_metrics import ServeMetrics
-from repro.obs.telemetry import TELEMETRY, now
+from repro.obs.telemetry import TELEMETRY, now, now_ns
 
 
 @dataclass
@@ -47,6 +53,9 @@ class Request:
     tokens: np.ndarray          # (n,) int32 prompt
     max_new: int = 16
     detok: List[int] = field(default_factory=list)
+    # when the request arrived, on the ``obs.telemetry.now`` clock; an open
+    # loop passes it so queue wait and TTFT count a late submit
+    arrival: Optional[float] = None
 
 
 _STOP = object()
@@ -70,24 +79,40 @@ class Scheduler:
 
     # ---------------------------------------------------------- detok side
     def _detok_loop(self):
-        while True:
-            item = self._detok_q.get()
-            try:
-                if item is _STOP:
-                    return
-                rid, tok = item
-                self.outputs.setdefault(rid, []).append(tok)
-                if self._detok_fn is not None:
+        item = self._detok_q.get()
+        while item is not _STOP:
+            # one span per run of items handled without blocking
+            with TELEMETRY.span("serve.detok") as sp:
+                tokens, lag_ns = 0, 0
+                while item is not None and item is not _STOP:
+                    rid, tok, t_put = item
+                    if t_put is not None:  # stamped while telemetry was on
+                        tokens += 1
+                        lag_ns += now_ns() - t_put
+                    self._deliver(rid, tok)
                     try:
-                        self._detok_fn(rid, tok)
-                    except BaseException as e:  # noqa: BLE001 - user code
-                        # record + count, keep draining: a poisoned
-                        # callback must not strand queue.join() forever
-                        if self._detok_exc is None:
-                            self._detok_exc = e
-                        self.metrics.count_detok_error()
-            finally:
-                self._detok_q.task_done()
+                        item = self._detok_q.get_nowait()
+                    except queue.Empty:
+                        item = None
+                sp.annotate(tokens=tokens, lag_us_sum=lag_ns / 1e3)
+            if item is None:
+                item = self._detok_q.get()
+        self._detok_q.task_done()
+
+    def _deliver(self, rid, tok):
+        try:
+            self.outputs.setdefault(rid, []).append(tok)
+            if self._detok_fn is not None:
+                try:
+                    self._detok_fn(rid, tok)
+                except BaseException as e:  # noqa: BLE001 - user code
+                    # record + count, keep draining: a poisoned
+                    # callback must not strand queue.join() forever
+                    if self._detok_exc is None:
+                        self._detok_exc = e
+                    self.metrics.count_detok_error()
+        finally:
+            self._detok_q.task_done()
 
     def _raise_detok(self):
         """Surface the first detokenize-callback exception on the caller's
@@ -98,12 +123,14 @@ class Scheduler:
             raise exc
 
     def _emit(self, pairs):
-        for rid, tok in pairs:
-            self._detok_q.put((rid, tok))
+        with TELEMETRY.span("serve.emit"):
+            stamp = TELEMETRY.enabled
+            for rid, tok in pairs:
+                self._detok_q.put((rid, tok, now_ns() if stamp else None))
 
     # --------------------------------------------------------- device side
     def submit(self, req: Request):
-        self.metrics.on_submit(req.rid)
+        self.metrics.on_submit(req.rid, req.arrival)
         self.backlog.put(req)
         self._pending += 1
         self.metrics.set_backlog(self.backlog.qsize())

@@ -1,10 +1,16 @@
 """Telemetry tier-1 suite (repro.obs): span nesting/attribution, the
 disabled-mode no-op fast path, exact-sample histogram percentiles, manifest
 round-trips (bench JSON rows + PTQ checkpoint meta), JSONL sink validation,
-compile attribution, and the zero-compile contract for instrumented warm
+compile attribution, the zero-compile contract for instrumented warm
 paths (telemetry is host-side only, so a warmed jit under live spans must
-never trace or compile again)."""
+never trace or compile again), spans in the profiler trace on its clock,
+collections as spans, and span ids across threads."""
+import gc
+import glob
 import json
+import sys
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +18,8 @@ import numpy as np
 import pytest
 
 from benchmarks.run import rows_to_records, stamp_records
+from jax.profiler import ProfileData
+
 from repro.obs import compile_events
 from repro.obs.serve_metrics import ServeMetrics, percentiles_from_events
 from repro.obs.sink import (SCHEMA_VERSION, JsonlSink, ListSink, RunManifest,
@@ -227,3 +235,148 @@ def test_compile_attribution_and_zero_compile_warm_path(no_retrace):
                 with TELEMETRY.span("obs.test.warm", i=i) as sp:
                     sp.block_on(f(x))
     assert sum(1 for r in warm_sink.records if r["kind"] == "span") == 3
+
+
+# ------------------------------------------------- spans in the profiler trace
+def _host_events(trace_dir, prefix):
+    """Host events of a written trace whose name starts with ``prefix``:
+    (name, start ns, duration ns, stats dict)."""
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefix):
+                    out.append((e.name, e.start_ns, e.duration_ns,
+                                dict(e.stats)))
+    return out
+
+
+def test_span_lands_in_profiler_trace_on_one_clock(tmp_path):
+    """An enabled span under an active profiler trace is a host event of
+    the same name with its ``span_id`` as a stat and the sink's duration;
+    sink starts and trace starts differ by one constant offset."""
+    sink = ListSink()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TELEMETRY.enabled_scope(sink=sink):
+            for i in range(3):
+                with TELEMETRY.span("obs.test.traced", i=i):
+                    time.sleep(0.004)
+                    with TELEMETRY.span("obs.test.traced_child"):
+                        time.sleep(0.002)
+                time.sleep(0.003)
+    finally:
+        jax.profiler.stop_trace()
+    recs = {r["id"]: r for r in sink.records if r["kind"] == "span"}
+    events = _host_events(str(tmp_path), "obs.test.traced")
+    assert len(events) == len(recs) == 6
+    offsets = []
+    for name, start, dur, stats in events:
+        rec = recs[stats["span_id"]]
+        assert rec["name"] == name
+        assert abs(dur / 1e3 - rec["dur_us"]) <= max(0.05 * rec["dur_us"],
+                                                     50.0)
+        offsets.append(rec["t0_ns"] - start)
+    assert max(offsets) - min(offsets) <= 50_000
+    child = [r for r in recs.values() if r["name"] == "obs.test.traced_child"]
+    assert all(recs[c["parent_id"]]["name"] == "obs.test.traced"
+               for c in child)
+
+
+def test_disabled_span_writes_no_host_event(tmp_path):
+    """Disabled telemetry under an active trace: no host event, no record,
+    no timing histogram."""
+    assert not TELEMETRY.enabled
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TELEMETRY.span("obs.test.off_traced") as sp:
+            sp.annotate(x=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert sp is _NULL_SPAN
+    assert _host_events(str(tmp_path), "obs.test.off_traced") == []
+    assert "span.obs.test.off_traced" not in TELEMETRY.histograms
+
+
+def test_gc_collections_are_spans_while_enabled():
+    """A generation-2 collection inside an open span is an ``obs.gc`` span
+    child of it; the hook is gone once telemetry is disabled again."""
+    sink = ListSink()
+    with TELEMETRY.enabled_scope(sink=sink):
+        with TELEMETRY.span("obs.test.gc_parent"):
+            gc.collect(2)
+    assert TELEMETRY._on_gc not in gc.callbacks
+    spans = [r for r in sink.records if r["kind"] == "span"]
+    parent, = [r for r in spans if r["name"] == "obs.test.gc_parent"]
+    gcs = [r for r in spans if r["name"] == "obs.gc"
+           and r["attrs"]["generation"] == 2]
+    assert gcs and all(r["parent_id"] == parent["id"] for r in gcs)
+    assert all(r["attrs"]["collected"] >= 0 for r in gcs)
+    gc.collect(2)
+    n = len(sink.records)
+    gc.collect(2)
+    assert len(sink.records) == n
+
+
+def test_validator_requires_span_ids_from_schema_2(tmp_path):
+    """Span records of schema 2 carry id, parent id and start; one that
+    lacks them is refused, one of schema 1 is read as it was written."""
+    path = str(tmp_path / "events.jsonl")
+    sink = JsonlSink(path)
+    with TELEMETRY.enabled_scope(sink=sink, manifest=current_manifest()):
+        with TELEMETRY.span("obs.test.ids"):
+            pass
+    sink.close()
+    assert validate_events(path) == []
+    with open(path, "a") as fh:
+        fh.write(json.dumps({"kind": "span", "schema": 1, "name": "old",
+                             "dur_us": 1.0}) + "\n")
+    assert validate_events(path) == []
+    with open(path, "a") as fh:
+        fh.write(json.dumps({"kind": "span", "schema": 2, "name": "new",
+                             "dur_us": 1.0}) + "\n")
+    errs = validate_events(path)
+    assert {e.split(": ")[-1] for e in errs} == {
+        "span record has no valid 'id'", "span record has no valid 't0_ns'",
+        "span record has no valid 'parent_id'"}
+
+
+def test_spans_from_many_threads_keep_ids_and_parents():
+    """Threads that open spans at once, with the interpreter switching
+    threads as often as it can, still give every span its own id and each
+    child the parent from its own thread."""
+    sink = ListSink()
+    n_threads, n_spans = 16, 200
+
+    def work(t):
+        for i in range(n_spans):
+            with TELEMETRY.span("obs.test.stress_outer", thread=t):
+                with TELEMETRY.span("obs.test.stress_inner", thread=t):
+                    pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with TELEMETRY.enabled_scope(sink=sink):
+            threads = [threading.Thread(target=work, args=(t,))
+                       for t in range(n_threads)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    spans = {r["id"]: r for r in sink.records if r["kind"] == "span"
+             and r["name"].startswith("obs.test.stress")}
+    assert len(spans) == 2 * n_threads * n_spans
+    for r in spans.values():
+        if r["name"] == "obs.test.stress_inner":
+            parent = spans[r["parent_id"]]
+            assert parent["name"] == "obs.test.stress_outer"
+            assert parent["attrs"]["thread"] == r["attrs"]["thread"]
+        else:
+            assert r["parent_id"] is None

@@ -1,6 +1,9 @@
 """Serving-engine tier-1 suite (repro.serve): bucketed-prefill bit parity,
 slot recycling, retrace flatness under mixed occupancy, the int8 KV HBM
-win, and the machine-readable capability-degradation contract."""
+win, the machine-readable capability-degradation contract, and the serve
+path's telemetry spans."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,8 +15,11 @@ from repro.core.quant_config import QuantRecipe
 from repro.core.reconstruct import quantize_blocks
 from repro.data import CalibrationSet, SyntheticTokens
 from repro.models import build_model
+from repro.obs import ListSink
+from repro.obs.telemetry import TELEMETRY, now
 from repro.serve import (EngineConfig, KVQuantUnsupported, Request,
                          Scheduler, ServeEngine, serve_capability)
+from repro.serve import engine as seng
 from repro.serve import kv as skv
 
 MAX_LEN = 32  # engine buckets: [8, 16, 32]
@@ -277,3 +283,84 @@ def test_prefill_latency_histogram_accumulates(engine, deploy_lm):
     assert s["count"] == before + 2, "prefill histogram overwrote a sample"
     assert {"count", "mean", "p50", "p95", "max"} <= set(s)
     assert 0 < s["p50"] <= s["p95"] <= s["max"]
+
+
+# ------------------------------------------------------- spans on the serve path
+def test_detok_spans_time_the_queue_lag(engine, deploy_lm):
+    """With a slow client callback, the detokenize thread records one
+    ``serve.detok`` span per run of queued tokens (not one per token), its
+    ``tokens`` count every stamped token, and their mean lag is at least
+    half the callback's time: a token waits behind those put with it."""
+    cfg = deploy_lm[0]
+    slow_s = 0.01
+    reqs = [Request(300 + i, p, max_new=6)
+            for i, p in enumerate(_prompts([5, 9, 4], cfg.vocab, seed=4))]
+    sink = ListSink()
+    with TELEMETRY.enabled_scope(sink=sink):
+        with Scheduler(engine, detokenize=lambda r, t: time.sleep(slow_s)
+                       ) as sched:
+            outs = sched.run(reqs)
+    n_tok = sum(len(v) for v in outs.values())
+    detok = [r for r in sink.records
+             if r["kind"] == "span" and r["name"] == "serve.detok"]
+    assert sum(r["attrs"]["tokens"] for r in detok) == n_tok == 18
+    assert len(detok) < n_tok
+    lag_us = sum(r["attrs"]["lag_us_sum"] for r in detok)
+    assert lag_us / n_tok >= 0.5 * slow_s * 1e6
+    assert all(r["parent_id"] is None for r in detok)  # its own thread
+    names = {r["name"] for r in sink.records if r["kind"] == "span"}
+    assert {"serve.pump", "serve.admit", "serve.prefill.dispatch",
+            "serve.prefill.wait", "serve.decode.dispatch",
+            "serve.decode.wait", "serve.decode.bookkeep",
+            "serve.emit"} <= names
+
+
+def test_request_arrival_starts_its_queue_wait(engine, deploy_lm):
+    """A request that arrived 50 ms before its submit waits at least 50 ms
+    by the scheduler's own accounting."""
+    cfg = deploy_lm[0]
+    p, = _prompts([6], cfg.vocab, seed=5)
+    before = engine.metrics.queue_wait_us.count
+    with Scheduler(engine) as sched:
+        sched.run([Request(400, p, max_new=2, arrival=now() - 0.05)])
+    assert engine.metrics.queue_wait_us.count == before + 1
+    assert engine.metrics.queue_wait_us.values[before] >= 50_000
+
+
+def test_serve_path_flat_and_jaxpr_identical_under_profiler(
+        engine, deploy_lm, no_retrace, tmp_path):
+    """With telemetry enabled and a profiler trace active, every span of
+    the serve path writes its annotation and the engine still neither
+    traces nor compiles (``no_retrace(0, xla_budget=0)``), and the decode
+    and prefill jaxprs are identical to those traced with both off."""
+    cfg, model, qparams, ctx = deploy_lm
+    ecfg = engine.cfg
+    state = seng.init_state(model, ecfg)
+    meta = {k: state[k] for k in ("tokens", "pos", "remaining")}
+    G, b = ecfg.prefill_group, ecfg.min_bucket
+    pargs = (qparams, state, jnp.zeros((G, b), jnp.int32),
+             jnp.ones((G,), jnp.int32), jnp.zeros((G,), jnp.int32),
+             jnp.ones((G,), jnp.int32))
+
+    def jaxprs():
+        return (str(jax.make_jaxpr(seng.make_decode(model, ctx, ecfg))(
+                    qparams, state["cache"], meta)),
+                str(jax.make_jaxpr(seng.make_prefill(model, ctx, ecfg, b))(
+                    *pargs)))
+
+    plain = jaxprs()
+    reqs = [Request(500 + i, p, max_new=3)
+            for i, p in enumerate(_prompts([4, 11, 20], cfg.vocab, seed=6))]
+    sink = ListSink()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TELEMETRY.enabled_scope(sink=sink):
+            assert jaxprs() == plain
+            with no_retrace(0, xla_budget=0):
+                with Scheduler(engine) as sched:
+                    outs = sched.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    assert sorted(outs) == [500, 501, 502]
+    assert any(r["kind"] == "span" and r["name"] == "serve.decode_step"
+               for r in sink.records)
