@@ -31,14 +31,21 @@ _kv_dequantize = skv.kv_dequantize
 
 def _cache_write(buf, li, pos, val):
     """Insert one token's (B, 1, ...) entry into layer ``li`` of a
-    (L, B, Smax, ...) cache at ``pos`` — a scalar (uniform batch) or (B,)
-    (serving slots, each at its own depth)."""
-    val = val.astype(buf.dtype)
-    pos = jnp.asarray(pos)
-    if pos.ndim:
-        return buf.at[li, jnp.arange(val.shape[0]), pos].set(val[:, 0])
+    (L, B, Smax, ...) cache at the scalar ``pos`` (uniform batch)."""
     return jax.lax.dynamic_update_slice(
-        buf, val[None], (li, 0, pos) + (0,) * (buf.ndim - 3))
+        buf, val.astype(buf.dtype)[None], (li, 0, pos) + (0,) * (buf.ndim - 3))
+
+
+def _cache_append(cache, nm, li, pos, val):
+    """Append one token to ``cache[nm]``'s layer ``li`` and return that
+    layer after the write. ``pos`` is a scalar (uniform batch) or (B,)
+    (serving slots, each at its own depth; see ``skv.append_token``)."""
+    if pos.ndim:
+        cache[nm], layer = skv.append_token(cache[nm], li, pos, val,
+                                            skv.append_form(cache))
+        return layer
+    cache[nm] = _cache_write(cache[nm], li, pos, val)
+    return jax.lax.dynamic_index_in_dim(cache[nm], li, 0, False)
 
 
 def _layer_names(layers, layer_off: int):
@@ -403,25 +410,19 @@ class TransformerLM:
                 q = common.apply_rope(q, sin, cos)
                 k = common.apply_rope(k, sin, cos)
                 if "k_scale" in cache:
+                    layer = []
                     for nm, t in (("k", k), ("v", v)):
                         codes, scl = skv.kv_quantize(t)
-                        cache[nm] = _cache_write(cache[nm], li, pos, codes)
-                        cache[f"{nm}_scale"] = _cache_write(
-                            cache[f"{nm}_scale"], li, pos, scl)
-                    layer = [jax.lax.dynamic_index_in_dim(cache[nm], li, 0,
-                                                          False)
-                             for nm in ("k", "k_scale", "v", "v_scale")]
+                        layer += [
+                            _cache_append(cache, nm, li, pos, codes),
+                            _cache_append(cache, f"{nm}_scale", li, pos, scl)]
                     # dequant-free: scales fold in after the contractions,
                     # the cache never rematerializes in k.dtype
                     o = skv.int8_decode_attention(q, *layer, pos,
                                                   window=cfg.local_window)
                 else:
-                    cache["k"] = _cache_write(cache["k"], li, pos, k)
-                    cache["v"] = _cache_write(cache["v"], li, pos, v)
-                    k_l = jax.lax.dynamic_index_in_dim(cache["k"], li, 0,
-                                                       False)
-                    v_l = jax.lax.dynamic_index_in_dim(cache["v"], li, 0,
-                                                       False)
+                    k_l = _cache_append(cache, "k", li, pos, k)
+                    v_l = _cache_append(cache, "v", li, pos, v)
                     o = attn.decode_attention(q, k_l, v_l, pos,
                                               window=cfg.local_window)
                 a_out = ctx.linear(f"{name}.wo", o.reshape(B, 1, H * Dh),

@@ -359,6 +359,7 @@ class ServeEngine:
             "hbm_per_slot_bytes": self.hbm_per_slot_bytes(),
             "hbm_per_slot_MiB": self.hbm_per_slot_mib(),
             "kv_quant": self.cfg.kv_quant,
+            "kv_append": skv.append_form(self.state["cache"]),
             "requests": self.metrics.request_summary(),
             "manifest": current_manifest().brief(),
         }

@@ -38,6 +38,8 @@ KV_EPS = 1e-6
 KV_QMAX = 127.0
 # smallest scale the quantizer can store: the contract QL303 proves against
 KV_SCALE_MIN = KV_EPS / KV_QMAX
+# width of a TPU vector register's lane axis
+LANES = 128
 
 
 class KVQuantUnsupported(ValueError):
@@ -111,6 +113,49 @@ def int8_decode_attention(q: jax.Array, k_codes: jax.Array,
     pv = p * v_scale[..., 0].transpose(0, 2, 1)[:, :, None, None, :]
     out = jnp.einsum("bhgqk,bkhd->bqhgd", pv, v_codes.astype(jnp.float32))
     return out.reshape(B, 1, Hq, v_codes.shape[-1]).astype(q.dtype)
+
+
+def append_form(cache) -> str:
+    """How the slot decode appends each slot's token to the stacked
+    (L, B, Smax, ...) cache leaves: ``"scatter"`` or ``"select"``, one
+    form for every leaf, codes and scales, chosen by the head dim of K.
+
+    A head dim that fills whole 128-wide lane tiles (128) keeps the code
+    leaves row-major on a TPU, where the per-slot scatter writes in place.
+    A narrower one (64) would be padded, so the chip lays the leaf out with
+    the sequence axis in the lanes; a scatter into that axis makes XLA copy
+    the whole stacked leaf to another layout and back on every step. The
+    positional select writes in the layout the leaf already has. The scales
+    follow the codes: in a stacked-scan decode the per-layer select of a
+    scale costs more than the whole-scale copies it saves.
+    """
+    return "scatter" if cache["k"].shape[-1] % LANES == 0 else "select"
+
+
+def append_token(buf: jax.Array, li, pos: jax.Array, val: jax.Array,
+                 form: str) -> Tuple[jax.Array, jax.Array]:
+    """Write each slot's (B, 1, ...) entry at its own position ``pos`` (B,)
+    into layer ``li`` of a (L, B, Smax, ...) cache leaf, in ``form`` (see
+    :func:`append_form`). Returns the leaf and its layer ``li`` after the
+    write, which attention reads. Both forms leave the same bits; a
+    position at or past Smax writes nothing."""
+    val = val.astype(buf.dtype)
+    if form == "scatter":
+        buf = buf.at[li, jnp.arange(val.shape[0]), pos].set(val[:, 0])
+        return buf, jax.lax.dynamic_index_in_dim(buf, li, 0, False)
+    hit = jnp.arange(buf.shape[2])[None, :] == pos[:, None]
+    hit = hit.reshape(hit.shape + (1,) * (buf.ndim - 3))
+    layer = jnp.where(hit, val,
+                      jax.lax.dynamic_index_in_dim(buf, li, 0, False))
+    if buf.shape[-1] == 1:
+        # a scale layer: attention wants it in another layout than the
+        # stacked scales have. Held apart by the barrier, only this small
+        # layer is laid out anew, never the whole stacked leaf.
+        layer = jax.lax.optimization_barrier(layer)
+        return jax.lax.dynamic_update_index_in_dim(buf, layer, li, 0), layer
+    # a code layer is read back from the written cache: one copy of it
+    buf = jax.lax.dynamic_update_index_in_dim(buf, layer, li, 0)
+    return buf, jax.lax.dynamic_index_in_dim(buf, li, 0, False)
 
 
 def cache_bytes(cache) -> int:

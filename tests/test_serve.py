@@ -177,6 +177,12 @@ def test_int8_kv_halves_hbm_per_slot(deploy_lm):
     assert skv.hbm_per_slot_bytes(c8, slots) * slots == skv.cache_bytes(c8)
 
 
+def test_stats_report_the_kv_append_form(engine):
+    """``stats()`` names the form the compiled decode appends K/V in: the
+    smoke LM's head dim of 16 takes the positional select."""
+    assert engine.stats()["kv_append"] == "select"
+
+
 def test_kv_scales_floored_above_subnormal(deploy_lm):
     """Stored KV scales obey the QL303 contract: >= KV_SCALE_MIN even for
     an all-zero append (the absmax floor), far above float32 tiny."""

@@ -1,5 +1,7 @@
 """Compile-only checks: the serving kernels at smollm-135m's widths must be
-accepted by the TPU compiler for a described (not attached) v5e chip.
+accepted by the TPU compiler for a described (not attached) v5e chip, and
+the engine's decode at the benchmark's engine sizes must append K/V without
+relayouting the whole cache.
 
 Interpret-mode parity (test_kernels, test_deploy_parity, QL304) cannot show
 what Mosaic refuses: casts it has no lowering for, dot operand types, tile
@@ -7,16 +9,22 @@ alignment, VMEM limits. Nothing here runs, so nothing here is a timing. The
 topology is described inside a fixture, never at import: only the worker
 that runs these tests loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core.context import QuantCtx
 from repro.kernels.dequant_matmul_w4 import (dequant_matmul_batched,
                                              dequant_matmul_w4,
                                              dequant_matmul_w8)
 from repro.kernels.qmatmul_int8 import qmatmul_int8
+from repro.models import build_model
+from repro.serve import kv as skv
+from repro.serve.engine import EngineConfig, init_state, make_decode
 
 _CFG = get_config("smollm-135m")
 #: (K, N) of the attention q/o, k/v, and MLP up/down projections
@@ -133,3 +141,57 @@ def test_data_parallel_ptq_student_compiles_for_v5e_2x2(topo, monkeypatch):
                                  sds(a_c, P())).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") == len(block.sites)
+
+
+#: the benchmark's serving engines: (config, slots, layers unrolled as the
+#: mixed-precision deploy path runs them, append form, dtypes of the whole
+#: leaves its decode may still copy). olmo-1b's scatter still relayouts its
+#: f32 scales (67 MB each), as before; never its int8 codes.
+ENGINES = {"smollm-135m": (64, True, "select", set()),
+           "olmo-1b": (32, False, "scatter", {"f32"})}
+
+
+def _whole_cache_copies(name, one_chip, slots, unrolled):
+    """Dtypes of the ``copy`` ops in the compiled slot decode whose result
+    has the shape of a whole stacked cache leaf (a relayout of the whole
+    leaf), and the cache's shapes."""
+    model = build_model(get_config(name))
+    ecfg = EngineConfig(slots=slots, max_len=2048, kv_quant=True)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    if unrolled:
+        layer = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:],
+                                                            a.dtype),
+                             params["layers"])
+        params = {**params, "layers": [layer] * model.cfg.n_layers}
+    state = jax.eval_shape(lambda: init_state(model, ecfg))
+    on = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    meta = {k: state[k] for k in ("tokens", "pos", "remaining")}
+    hlo = (jax.jit(make_decode(model, QuantCtx(), ecfg), donate_argnums=(1,))
+           .lower(on(params), on(state["cache"]), on(meta)).compile()
+           .as_text())
+    leaves = {tuple(a.shape) for a in jax.tree.leaves(state["cache"])}
+    copies = [m.group(1) for m in re.finditer(
+        r"= (\w+)\[([\d,]+)\]\{[^}]*\} copy\(", hlo)
+        if tuple(int(d) for d in m.group(2).split(",")) in leaves]
+    return copies, state["cache"]
+
+
+@pytest.mark.parametrize("name", sorted(ENGINES))
+def test_decode_appends_without_relayouting_the_cache(one_chip, name):
+    """The slot decode appends each token in the layout the chip gives the
+    stacked cache: no whole copy of the int8 codes, for either head dim."""
+    slots, unrolled, form, may_copy = ENGINES[name]
+    copies, cache = _whole_cache_copies(name, one_chip, slots, unrolled)
+    assert skv.append_form(cache) == form
+    assert set(copies) <= may_copy, copies
+
+
+def test_scatter_into_a_sequence_minor_cache_relayouts_it(one_chip,
+                                                          monkeypatch):
+    """Control for the test above: the per-slot scatter, at smollm-135m's
+    head dim of 64, copies whole int8 code leaves, so the check sees them."""
+    monkeypatch.setattr(skv, "append_form", lambda cache: "scatter")
+    copies, _ = _whole_cache_copies("smollm-135m", one_chip, 64, False)
+    assert "s8" in copies
